@@ -22,6 +22,8 @@ def main(argv=None) -> None:
         help="dump a Perfetto/Chrome trace of the whole run to PATH")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_control_plane, bench_detection, bench_durability,
                    bench_fig2_ingestion, bench_fig4_transform,
                    bench_kernels, bench_observability, bench_roofline,

@@ -8,12 +8,14 @@ retrieve the forecast by semantics.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Castor, ModelDeployment, Schedule, DAY, HOUR
 from repro.forecast import LinearForecaster
 from repro.timeseries.transforms import mape
 
 
 def main():
+    enable_compile_cache()
     castor = Castor()
 
     # (1) ingest an irregular energy time-series for 35 days

@@ -11,11 +11,13 @@ import jax
 import numpy as np
 
 from repro.arch import model as M
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.serve import Request, ServeEngine
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--requests", type=int, default=10)

@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Castor, ModelDeployment, Schedule, DAY, HOUR
 from repro.forecast import (PAPER_MODELS, EnergyFromCurrentModel)
 from repro.timeseries.ingest import SiteSpec, build_site, ingest_current_feed
@@ -23,6 +24,7 @@ from repro.timeseries.transforms import mape
 
 
 def main(executor: str = "fleet"):
+    enable_compile_cache()
     castor = Castor()
     t_end = 50 * DAY
     site = build_site(castor, SiteSpec("CY", n_prosumers=8, n_feeders=2,

@@ -5,10 +5,12 @@ async sharded checkpoints, an injected node failure, restore-and-continue.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="qwen3-1.7b")

@@ -26,19 +26,6 @@ Axes = Union[None, str, Tuple[str, ...]]
 PAD_OK: set = set()         # logical axes where uneven sharding would be allowed
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map: jax >= 0.5 exposes ``jax.shard_map``
-    (replication check renamed check_vma); 0.4.x ships it under
-    jax.experimental with check_rep."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # Fleet-bin sharding: partition a megabatch's INSTANCE axis over devices.
 # ---------------------------------------------------------------------------
@@ -84,8 +71,8 @@ def fleet_sharded(fn, mesh, *, replicated_argnums: Tuple[int, ...] = (),
 
     def build(nargs: int):
         in_specs = tuple(P() if i in repl else P(axis) for i in range(nargs))
-        return jax.jit(shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
-                                        out_specs=P(axis)))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=P(axis), check_vma=False))
 
     def wrapper(*args):
         cache_k = None if key is None else (key, mesh, repl, len(args))

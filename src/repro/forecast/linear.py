@@ -5,16 +5,36 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.scipy.linalg import cho_factor, cho_solve
 
 from .base import ForecastModelBase
 from .features import bucket_n, edge_pad, note_trace
 
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+#: iterative-refinement steps after the normal-equations solve
+_REFINE_STEPS = 2
+
+
 def _ridge_fit(X, y, lam=1e-2):
+    """Ridge solve in float32 that matches a float64 solve.
+
+    The normal equations square the design's condition number, so solving
+    them once in float32 is off by about cond(Xb)^2 * eps (measured up to
+    3e-3 on hourly smart-grid designs). Each refinement step re-solves for
+    the residual taken from the design itself, which brings the error down
+    to about cond(Xb) * eps. Every matmul is pinned to HIGHEST: a TPU's
+    default float32 matmul is a single bfloat16 pass."""
     Xb = jnp.concatenate([X, jnp.ones(X.shape[:-1] + (1,))], -1)
-    A = Xb.T @ Xb + lam * jnp.eye(Xb.shape[-1])
-    b = Xb.T @ y
-    return jnp.linalg.solve(A, b)
+    A = jnp.matmul(Xb.T, Xb, precision=_HIGHEST) + lam * jnp.eye(Xb.shape[-1])
+    chol = cho_factor(A)
+    theta = cho_solve(chol, jnp.matmul(Xb.T, y, precision=_HIGHEST))
+    for _ in range(_REFINE_STEPS):
+        resid = y - jnp.matmul(Xb, theta, precision=_HIGHEST)
+        r = jnp.matmul(Xb.T, resid, precision=_HIGHEST) - lam * theta
+        theta = theta + cho_solve(chol, r)
+    return theta
 
 
 def _ridge_fit_counted(X, y, lam=1e-2):
@@ -84,7 +104,8 @@ class LinearForecaster(ForecastModelBase):
     @classmethod
     def _fleet_predict_traced(cls, stacked, x):
         th = jnp.asarray(stacked["theta"], jnp.float32)
-        return jnp.einsum("nf,nf->n", x, th[:, :-1]) + th[:, -1]
+        return jnp.einsum("nf,nf->n", x, th[:, :-1],
+                          precision=_HIGHEST) + th[:, -1]
 
     @classmethod
     def _device_predict_factory(cls, spec, statics):

@@ -4,11 +4,10 @@ Every kernel exposes ``op(..., impl=None)`` where impl is one of
     "xla"               pure-jnp (chunked where applicable) — CPU default
     "pallas"            real Pallas lowering — TPU default
     "pallas_interpret"  Pallas interpret=True — CPU validation of kernel bodies
-``None`` resolves via :func:`default_impl` (overridable with REPRO_KERNEL_IMPL).
+``None`` resolves via :func:`default_impl`: the backend chooses. Tests that
+want another path pass ``impl=`` explicitly.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -16,14 +15,11 @@ VALID = ("xla", "pallas", "pallas_interpret")
 
 
 def default_impl() -> str:
-    env = os.environ.get("REPRO_KERNEL_IMPL")
-    if env:
-        assert env in VALID, env
-        return env
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def resolve(impl: str | None) -> str:
     impl = impl or default_impl()
-    assert impl in VALID, impl
+    if impl not in VALID:
+        raise ValueError(f"unknown kernel impl {impl!r}; expected {VALID}")
     return impl

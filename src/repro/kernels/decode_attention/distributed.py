@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ...distributed.sharding import shard_map_compat as _shard_map
-
 NEG_INF = -1e30
 
 
@@ -64,9 +62,9 @@ def decode_attention_distributed(q, k_cache, v_cache, lengths, *, mesh,
         l = jax.lax.psum(l * corr, seq_axis)
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, seq_axis, None, None),
                   P(bspec, seq_axis, None, None), P(bspec)),
-        out_specs=P(bspec, None, None),
+        out_specs=P(bspec, None, None), check_vma=False,
     )(q, k_cache, v_cache, lengths)

@@ -4,7 +4,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from ..common import resolve
 from .ref import fleet_mlp_reference
@@ -20,35 +19,21 @@ def invocation_count() -> int:
     return _invocations
 
 
-def _pad0(a, pad):
-    return jnp.concatenate(
-        [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)], axis=0)
-
-
-@partial(jax.jit, static_argnames=("impl", "block_n"))
-def _fleet_mlp(x, weights, biases, *, impl: str | None = None, block_n: int = 8):
-    impl = resolve(impl)
+@partial(jax.jit, static_argnames=("impl",))
+def _fleet_mlp(x, weights, biases, *, impl: str):
     if impl == "xla":
         return fleet_mlp_reference(x, weights, biases)
     from .kernel import fleet_mlp_pallas
-    # the Pallas grid needs N % block_n == 0; a mesh-sharded fleet bin hands
-    # each device an arbitrary N/ndev slice, so zero-pad up to the block
-    # multiple here (zero weights -> zero outputs, sliced off below)
-    N = x.shape[0]
-    bn = min(block_n, N)
-    pad = (-N) % bn
-    if pad:
-        x = _pad0(x, pad)
-        weights = [_pad0(w, pad) for w in weights]
-        biases = [_pad0(b, pad) for b in biases]
-    out = fleet_mlp_pallas(x, weights, biases, block_n=bn,
-                           interpret=(impl == "pallas_interpret"))
-    return out[:N] if pad else out
+    # the block size divides N (see kernel.vmem_plan), so a mesh-sharded
+    # bin's per-device slice of any size runs without padding
+    return fleet_mlp_pallas(x, weights, biases,
+                            interpret=(impl == "pallas_interpret"))
 
 
-def fleet_mlp(x, weights, biases, *, impl: str | None = None, block_n: int = 8):
+def fleet_mlp(x, weights, biases, *, impl: str | None = None):
     """x: (N,b,F); weights/biases: per-layer stacks with leading N.
     Returns (N,b,O). ReLU between layers; final layer linear."""
     global _invocations
     _invocations += 1
-    return _fleet_mlp(x, weights, biases, impl=impl, block_n=block_n)
+    # resolved before the jit, so the implementation keys its cache
+    return _fleet_mlp(x, weights, biases, impl=resolve(impl))

@@ -9,6 +9,7 @@ ReLU between layers, final layer linear. float32 accumulation.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -16,7 +17,8 @@ def fleet_mlp_reference(x, weights, biases):
     h = x.astype(jnp.float32)
     n = len(weights)
     for i, (w, b) in enumerate(zip(weights, biases)):
-        h = jnp.einsum("nbf,nfh->nbh", h, w.astype(jnp.float32))
+        h = jnp.einsum("nbf,nfh->nbh", h, w.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
         h = h + b.astype(jnp.float32)[:, None, :]
         if i < n - 1:
             h = jnp.maximum(h, 0.0)

@@ -56,7 +56,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    ca = hlo_cost.xla_cost_properties(compiled)
+    ca = compiled.cost_analysis()
     text = compiled.as_text()
     cost = hlo_cost.analyze(text, n_dev)
 

@@ -484,13 +484,3 @@ def analyze(text: str, n_devices: int, *,
 
     ent = cost_of(entry)
     return ent
-
-
-def xla_cost_properties(compiled) -> dict:
-    """jax-version-portable ``compiled.cost_analysis()``: jax <= 0.4.x
-    returns a one-element list of property dicts, newer jax returns the
-    dict itself. Always hands back a plain dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)

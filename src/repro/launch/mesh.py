@@ -3,27 +3,19 @@ module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kw(n_axes: int) -> dict:
-    """``axis_types`` only where the running jax has it (>= 0.5); on older
-    versions every axis is Auto-typed already, so omitting it is identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod (TPU v5e); 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kw(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 #: axis name of the fleet-execution mesh (instance axis of a job bin)

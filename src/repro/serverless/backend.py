@@ -252,6 +252,15 @@ class ProcessBackend(InvocationBackend):
 
     def _spawn(self, worker_id: str) -> tuple:
         import multiprocessing as mp
+        import jax
+        # a TPU belongs to one process at a time: a worker that needs the
+        # chip this process holds would wait out spawn_timeout_s for it
+        if jax.default_backend() == "tpu" \
+                and self.env.get("JAX_PLATFORMS") != "cpu":
+            raise InvocationError(
+                f"{worker_id}: this process holds the TPU, so a JAX worker "
+                "process cannot use it (one process per chip); give the "
+                "workers JAX_PLATFORMS=cpu or use the InlineBackend")
         ctx = mp.get_context("spawn")
         task_q: "mp.Queue" = ctx.Queue()
         result_q: "mp.Queue" = ctx.Queue()

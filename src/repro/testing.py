@@ -16,13 +16,27 @@ FLEET_RTOL, FLEET_ATOL = 2e-3, 1e-3
 
 
 def subprocess_env(src_dir) -> dict:
-    """Minimal env for a jax subprocess (the device-count override must
-    precede jax init, hence subprocesses at all). JAX_PLATFORMS must be
-    forwarded: without it jax probes for accelerator plugins and hangs on
-    hosts with a baked-in (but absent) TPU toolchain."""
-    return {"PYTHONPATH": str(src_dir),
-            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
+    """Minimal env for a JAX subprocess (a device-count override must
+    precede JAX's initialisation, hence subprocesses at all).
+
+    ``JAX_PLATFORMS`` is forwarded only where it is set: tests set it to
+    ``cpu`` (tests/conftest.py), and a child then runs on the same backend
+    as its parent. Unset, the child takes JAX's default backend; it is
+    never sent to the CPU behind the caller's back.
+
+    A TPU belongs to one process at a time. When this process's backend
+    is a TPU it holds the chip, and a JAX child would fail or hang waiting
+    for it, so this raises instead."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "this process holds the TPU, so a JAX child process cannot "
+            "use it: run the work in this process (one process per chip)")
+    env = {"PYTHONPATH": str(src_dir),
+           "PATH": os.environ.get("PATH", "/usr/bin:/bin")}
+    if "JAX_PLATFORMS" in os.environ:
+        env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
+    return env
 
 
 HOUR = 3600.0

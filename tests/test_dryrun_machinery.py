@@ -2,11 +2,12 @@
 (subprocess: the device-count override must precede jax init, and the main
 test process must keep its single real device)."""
 import json
-import os
 import subprocess
 import sys
 import textwrap
 from pathlib import Path
+
+from repro.testing import subprocess_env
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -43,11 +44,9 @@ def test_cells_compile_and_analyze_on_8_devices():
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
         timeout=520,
-        env={"PYTHONPATH": str(Path(__file__).parent.parent / "src"),
-             "PATH": "/usr/bin:/bin",
-             # without this, jax probes for accelerator plugins and hangs
-             # on hosts with a baked-in (but absent) TPU toolchain
-             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
+        # the child inherits this test's JAX_PLATFORMS (cpu, from
+        # conftest.py): the forced 8-device override is a CPU feature
+        env=subprocess_env(Path(__file__).parent.parent / "src"),
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])

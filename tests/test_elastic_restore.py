@@ -2,11 +2,12 @@
 DIFFERENT (shrunken) mesh with new shardings — the node-failure recovery
 path claimed in DESIGN.md. Subprocess (needs 8 placeholder devices)."""
 import json
-import os
 import subprocess
 import sys
 import textwrap
 from pathlib import Path
+
+from repro.testing import subprocess_env
 
 SCRIPT = textwrap.dedent("""
     import os, tempfile, json
@@ -47,11 +48,9 @@ def test_checkpoint_restores_onto_shrunken_mesh():
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
         timeout=300,
-        env={"PYTHONPATH": str(Path(__file__).parent.parent / "src"),
-             "PATH": "/usr/bin:/bin",
-             # without this, jax probes for accelerator plugins and hangs
-             # on hosts with a baked-in (but absent) TPU toolchain
-             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
+        # the child inherits this test's JAX_PLATFORMS (cpu, from
+        # conftest.py): the forced 8-device override is a CPU feature
+        env=subprocess_env(Path(__file__).parent.parent / "src"),
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert json.loads(proc.stdout.strip().splitlines()[-1])["ok"]

@@ -163,3 +163,19 @@ def test_sharded_fleet_subprocess_smoke():
     devs = json.loads(proc.stdout.strip().splitlines()[-1])
     assert set(devs) == {"lr", "ann"}
     assert all(d < 1e-3 for d in devs.values()), devs
+
+
+def test_subprocess_env_forwards_platform_only_when_set(monkeypatch):
+    """A child runs on its parent's JAX_PLATFORMS, and on JAX's default
+    when none is set: it is never sent to the CPU behind the caller's
+    back."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert subprocess_env("src")["JAX_PLATFORMS"] == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "JAX_PLATFORMS" not in subprocess_env("src")
+
+
+def test_subprocess_env_refuses_when_parent_holds_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        subprocess_env("src")

@@ -21,7 +21,7 @@ def test_matches_xla_on_straightline():
     b = jax.ShapeDtypeStruct((256, 64), jnp.float32)
     c = _compile(f, a, b)
     got = hlo_cost.analyze(c.as_text(), 1)
-    xla = hlo_cost.xla_cost_properties(c)
+    xla = c.cost_analysis()
     # dot flops dominate; ours adds elementwise tanh
     assert abs(got.flops - xla["flops"]) / xla["flops"] < 0.05
     assert abs(got.bytes - xla["bytes accessed"]) / xla["bytes accessed"] < 0.2
@@ -42,7 +42,7 @@ def test_scan_multiplied_by_trip_count():
     dot_flops = 2 * 8 * 64 * 64
     assert got.flops == pytest.approx(T * dot_flops, rel=0.05)
     # XLA undercounts by the trip count (the motivating bug)
-    assert hlo_cost.xla_cost_properties(c)["flops"] == \
+    assert c.cost_analysis()["flops"] == \
         pytest.approx(dot_flops, rel=0.05)
 
 

@@ -151,6 +151,7 @@ def test_rwkv6_chunked_xla_moderate_decay(rng):
     (16, 4, 8, 32, 3, 4),
     (8, 1, 54, 64, 5, 8),      # ANN shape (4 hidden + out)
     (4, 2, 16, 16, 1, 2),      # single layer
+    (4, 1, 54, 512, 5, None),  # paper width, block_n from kernel.vmem_plan
 ])
 def test_fleet_mlp(rng, dtype, N, b, F, Hd, depth, block_n):
     x = _mk(rng, (N, b, F), dtype)
@@ -162,3 +163,20 @@ def test_fleet_mlp(rng, dtype, N, b, F, Hd, depth, block_n):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=TOL[dtype] * 10, rtol=TOL[dtype] * 10)
+
+
+@pytest.mark.parametrize("N,width,limit_raised", [
+    (1024, 64, False), (24, 64, False), (256, 512, False), (7, 512, False),
+    (16, 1024, True),
+])
+def test_fleet_mlp_vmem_plan(N, width, limit_raised):
+    """block_n divides N (no padded instances) and its double-buffered
+    blocks fit the default scoped VMEM; only an instance too large for it
+    raises the kernel's VMEM limit."""
+    from repro.kernels.fleet_mlp.kernel import _SCOPED_VMEM_BYTES, vmem_plan
+    widths = [width] * 4 + [1]
+    block_n, limit = vmem_plan(N, 1, 54, widths, jnp.float32)
+    assert N % block_n == 0
+    assert (limit is not None) == limit_raised
+    if limit is not None:
+        assert block_n == 1 and limit > _SCOPED_VMEM_BYTES

@@ -253,6 +253,27 @@ def test_process_backend_context_manager_reaps_and_cleans_storage():
     be.close()                                      # idempotent
 
 
+def test_process_backend_refuses_when_parent_holds_tpu(monkeypatch):
+    """A worker that needs the TPU this process holds would wait out the
+    whole spawn timeout: the backend must refuse at once and start no
+    process — unless the workers are pinned to the CPU."""
+    import jax
+    import multiprocessing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    Process = multiprocessing.get_context("spawn").Process
+    start, started = Process.start, []
+    monkeypatch.setattr(Process, "start",
+                        lambda self: started.append(self) or start(self))
+    with ProcessBackend(_mini_castor, n_workers=1) as be:
+        with pytest.raises(InvocationError, match="holds the TPU"):
+            be._worker("p0")
+    assert not started
+    with ProcessBackend(_mini_castor, n_workers=1,
+                        env={"JAX_PLATFORMS": "cpu"}) as be:
+        (proc, _tq, _rq), _lock = be._worker("p0")
+        assert proc.is_alive() and len(started) == 1
+
+
 def test_process_backend_smoke_matches_fleet():
     """Real spawned containers (JSON wire, artifact ship-back): forecasts
     equal the fleet executor's, versions persisted with the invoker's
