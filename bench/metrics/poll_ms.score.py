@@ -1,0 +1,8 @@
+"""Scheduler: mean ``scheduler.poll`` span per window tick, in ms."""
+
+
+def read(run):
+    spans = [s for s in run.spans if s.name == "scheduler.poll"]
+    if not spans or not run.ticks:
+        return None
+    return 1e3 * sum(s.t1 - s.t0 for s in spans) / len(run.ticks)
