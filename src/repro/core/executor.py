@@ -380,6 +380,27 @@ class FleetExecutor(_ExecBase):
         self.system.scheduler.mark_failed(job)
         return JobResult(job, False, dt, error=err)
 
+    def _forecasts(self, bin_jobs_: List[Job], latests,
+                   preds) -> List[Forecast]:
+        """One ``Forecast`` per scored job; ``preds`` holds ``(times,
+        values[, lower, upper])`` per job."""
+        fcs = []
+        for j, l, p in zip(bin_jobs_, latests, preds):
+            times, values = p[0], p[1]
+            lower, upper = (p[2], p[3]) if len(p) > 2 else (None, None)
+            fcs.append(Forecast(
+                deployment_name=j.deployment_name, signal=j.signal,
+                entity=j.entity, created_at=j.scheduled_at,
+                times=times if isinstance(times, np.ndarray)
+                else np.asarray(times),
+                values=values if isinstance(values, np.ndarray)
+                else np.asarray(values),
+                model_version=l.version,
+                rank=self.system.deployments.get(j.deployment_name).rank,
+                lower=None if lower is None else np.asarray(lower),
+                upper=None if upper is None else np.asarray(upper)))
+        return fcs
+
     def _run_bin(self, key, bin_jobs_: List[Job]) -> List[JobResult]:
         cls = self.system.registry.get(key[0], key[1])
         out: List[JobResult] = []
@@ -390,92 +411,99 @@ class FleetExecutor(_ExecBase):
         task = key[2]
         latests: List = []
         bands: List = []
-        if task == "detect":
-            # a detection compares against the band a LIVE poller would
-            # have had at its boundary (predictions.latest honors rank and
-            # at=, the same replay semantics scoring uses for versions); a
-            # context with no banded forecast yet fails ALONE, the rest of
-            # the bin detects
-            preds = self.system.predictions
-            at = float(bin_jobs_[0].scheduled_at)
-            bkey = (key[0], key[1],
-                    tuple(j.deployment_name for j in bin_jobs_))
-            # band cache across minutely polls: the resolved bands can
-            # only change when a forecast lands (mutations moves) or when
-            # a later ``at`` admits an already-stored forecast — excluded
-            # by max_created <= cached_at <= at
-            cached = self._detect_bands.get(bkey)
-            if cached is not None and cached[0] == preds.mutations \
-                    and preds.max_created <= cached[1] <= at:
-                bands = cached[2]
-            else:
-                n_bin = len(bin_jobs_)
-                present = []
+        tracer = get_tracer()
+        # the bin's host set-up: version lookups, bands, instances, mesh
+        with tracer.span("exec.prepare"):
+            if task == "detect":
+                # a detection compares against the band a LIVE poller would
+                # have had at its boundary (predictions.latest honors rank and
+                # at=, the same replay semantics scoring uses for versions); a
+                # context with no banded forecast yet fails ALONE, the rest of
+                # the bin detects
+                preds = self.system.predictions
+                at = float(bin_jobs_[0].scheduled_at)
+                bkey = (key[0], key[1],
+                        tuple(j.deployment_name for j in bin_jobs_))
+                # band cache across minutely polls: the resolved bands can
+                # only change when a forecast lands (mutations moves) or when
+                # a later ``at`` admits an already-stored forecast — excluded
+                # by max_created <= cached_at <= at
+                cached = self._detect_bands.get(bkey)
+                if cached is not None and cached[0] == preds.mutations \
+                        and preds.max_created <= cached[1] <= at:
+                    bands = cached[2]
+                else:
+                    n_bin = len(bin_jobs_)
+                    present = []
+                    for j in bin_jobs_:
+                        fc = preds.latest(j.signal, j.entity,
+                                          at=j.scheduled_at)
+                        if fc is None or fc.lower is None:
+                            out.append(self._fail(
+                                j, 0.0,
+                                f"no banded forecast for {j.signal}"
+                                f"@{j.entity}"))
+                        else:
+                            present.append(j)
+                            bands.append(fc)
+                    bin_jobs_ = present
+                    if not bin_jobs_:
+                        return out
+                    if len(present) == n_bin:       # full bin resolved: the
+                        if len(self._detect_bands) >= 8:    # bkey names match
+                            self._detect_bands.clear()
+                        self._detect_bands[bkey] = (preds.mutations, at,
+                                                    bands)
+            elif task != "train":
+                # a deployment that was never trained fails ALONE: exclude it
+                # from the megabatch, score the rest — one cold model must not
+                # poison the whole bin (at-least-once still holds per job).
+                # at=scheduled_at: a catch-up bin scores with the versions a
+                # live poller would have had at that boundary
+                present: List[Job] = []
                 for j in bin_jobs_:
-                    fc = preds.latest(j.signal, j.entity,
-                                      at=j.scheduled_at)
-                    if fc is None or fc.lower is None:
+                    mv = self.system.versions.get(j.deployment_name,
+                                                  at=j.scheduled_at)
+                    if mv is None:
                         out.append(self._fail(
                             j, 0.0,
-                            f"no banded forecast for {j.signal}"
-                            f"@{j.entity}"))
+                            f"no trained version for {j.deployment_name}"))
                     else:
                         present.append(j)
-                        bands.append(fc)
+                        latests.append(mv)
                 bin_jobs_ = present
                 if not bin_jobs_:
                     return out
-                if len(present) == n_bin:       # full bin resolved: the
-                    if len(self._detect_bands) >= 8:    # bkey names match
-                        self._detect_bands.clear()
-                    self._detect_bands[bkey] = (preds.mutations, at, bands)
-        elif task != "train":
-            # a deployment that was never trained fails ALONE: exclude it
-            # from the megabatch, score the rest — one cold model must not
-            # poison the whole bin (at-least-once still holds per job).
-            # at=scheduled_at: a catch-up bin scores with the versions a
-            # live poller would have had at that boundary
-            present: List[Job] = []
-            for j in bin_jobs_:
-                mv = self.system.versions.get(j.deployment_name,
-                                              at=j.scheduled_at)
-                if mv is None:
-                    out.append(self._fail(
-                        j, 0.0, f"no trained version for {j.deployment_name}"))
-                else:
-                    present.append(j)
-                    latests.append(mv)
-            bin_jobs_ = present
-            if not bin_jobs_:
-                return out
-        # detection is a host-side store compare, nothing to shard
-        mesh = None if task == "detect" else self._bin_mesh(bin_jobs_)
-        ndev = len(mesh.devices.flat) if mesh is not None else 1
-        pad = (-len(bin_jobs_)) % ndev
-        if task == "train":
-            instances = [self._instantiate(j, cls=cls) for j in bin_jobs_]
-        elif task == "detect":
-            ikey = bkey if len(bin_jobs_) == len(bkey[2]) else \
-                (key[0], key[1],
-                 tuple(j.deployment_name for j in bin_jobs_))
-            rev = self.system.deployments.revision
-            cached = self._detect_instances.get(ikey)
-            if cached is not None and cached[0] == rev:
-                _, instances, detect_ts_ids, detect_names = cached
-            else:
-                instances = [self._instantiate(j, latest=None, cls=cls)
+            # detection is a host-side store compare, nothing to shard
+            mesh = None if task == "detect" else self._bin_mesh(bin_jobs_)
+            ndev = len(mesh.devices.flat) if mesh is not None else 1
+            pad = (-len(bin_jobs_)) % ndev
+            if task == "train":
+                instances = [self._instantiate(j, cls=cls)
                              for j in bin_jobs_]
-                detect_ts_ids = [i.context.ts_id for i in instances]
-                detect_names = ([i.model_id for i in instances],
-                                [i.context.signal.name for i in instances],
-                                [i.context.entity.name for i in instances])
-                if len(self._detect_instances) >= 8:    # stale-rev bins
-                    self._detect_instances.clear()
-                self._detect_instances[ikey] = (rev, instances,
-                                                detect_ts_ids, detect_names)
-        else:       # versions already resolved above: no second lookup
-            instances = [self._instantiate(j, latest=mv, cls=cls)
-                         for j, mv in zip(bin_jobs_, latests)]
+            elif task == "detect":
+                ikey = bkey if len(bin_jobs_) == len(bkey[2]) else \
+                    (key[0], key[1],
+                     tuple(j.deployment_name for j in bin_jobs_))
+                rev = self.system.deployments.revision
+                cached = self._detect_instances.get(ikey)
+                if cached is not None and cached[0] == rev:
+                    _, instances, detect_ts_ids, detect_names = cached
+                else:
+                    instances = [self._instantiate(j, latest=None, cls=cls)
+                                 for j in bin_jobs_]
+                    detect_ts_ids = [i.context.ts_id for i in instances]
+                    detect_names = (
+                        [i.model_id for i in instances],
+                        [i.context.signal.name for i in instances],
+                        [i.context.entity.name for i in instances])
+                    if len(self._detect_instances) >= 8:    # stale-rev bins
+                        self._detect_instances.clear()
+                    self._detect_instances[ikey] = (
+                        rev, instances, detect_ts_ids, detect_names)
+            else:       # versions already resolved above: no second lookup
+                instances = [self._instantiate(j, latest=mv, cls=cls)
+                             for j, mv in zip(bin_jobs_, latests)]
         from ..forecast.base import rollout_cache_stats
         from ..forecast.features import trace_count
         kw = {"mesh": mesh}
@@ -487,11 +515,12 @@ class FleetExecutor(_ExecBase):
         try:
             if task == "train":
                 model_objs = cls.fleet_train(instances, **kw)
-                for j, mo in zip(bin_jobs_, model_objs):
-                    self.system.versions.save(
-                        j.deployment_name, mo, trained_at=j.scheduled_at,
-                        metadata={"fleet": True, "signal": j.signal,
-                                  "entity": j.entity})
+                with tracer.span("store.write"):
+                    for j, mo in zip(bin_jobs_, model_objs):
+                        self.system.versions.save(
+                            j.deployment_name, mo, trained_at=j.scheduled_at,
+                            metadata={"fleet": True, "signal": j.signal,
+                                      "entity": j.entity})
             elif task == "detect":
                 # ONE vectorized band-compare for the whole bin (one
                 # read_many, no per-sensor python loop) through the
@@ -500,29 +529,15 @@ class FleetExecutor(_ExecBase):
                     instances, bands,
                     now=float(bin_jobs_[0].scheduled_at),
                     ts_ids=detect_ts_ids, names=detect_names)
-                self.system.detections.save_many(records)
+                with tracer.span("store.write"):
+                    self.system.detections.save_many(records)
             else:
                 preds = cls.fleet_score(instances,
                                         [l.params for l in latests],
                                         **kw)
-                fcs = []
-                for j, l, p in zip(bin_jobs_, latests, preds):
-                    times, values = p[0], p[1]
-                    lower, upper = (p[2], p[3]) if len(p) > 2 else (None,
-                                                                    None)
-                    fcs.append(Forecast(
-                        deployment_name=j.deployment_name, signal=j.signal,
-                        entity=j.entity, created_at=j.scheduled_at,
-                        times=times if isinstance(times, np.ndarray)
-                        else np.asarray(times),
-                        values=values if isinstance(values, np.ndarray)
-                        else np.asarray(values),
-                        model_version=l.version,
-                        rank=self.system.deployments.get(
-                            j.deployment_name).rank,
-                        lower=None if lower is None else np.asarray(lower),
-                        upper=None if upper is None else np.asarray(upper)))
-                self.system.predictions.save_many(fcs)
+                with tracer.span("store.write"):
+                    self.system.predictions.save_many(
+                        self._forecasts(bin_jobs_, latests, preds))
             dt = time.perf_counter() - t0
             per = dt / max(len(bin_jobs_), 1)
             # dataclass __init__ per job is measurable at fleet width:
@@ -552,21 +567,8 @@ class FleetExecutor(_ExecBase):
             if self.runtime is not None:
                 stats.update(self.runtime.pop_stats())
             self.last_bin_stats.append(stats)
-            # absorb the bin's telemetry into the metrics registry (once
-            # per bin — off the per-job hot path)
-            m = get_metrics()
-            m.counter("exec.bins").inc()
-            m.counter("exec.jobs").inc(stats["jobs"])
-            m.histogram("exec.bin_seconds").observe(dt)
-            m.counter("exec.retraces").inc(stats["retraces"])
-            m.counter("exec.rollout_cache_hits").inc(
-                stats["rollout_cache_hits"])
-            m.counter("exec.rollout_cache_misses").inc(
-                stats["rollout_cache_misses"])
-            if stats["cache_hit"]:
-                m.counter("runtime.cache_hits").inc()
-            if stats["delta_rows"]:
-                m.counter("runtime.delta_rows").inc(stats["delta_rows"])
+            # once per bin, off the per-job hot path
+            get_metrics().histogram("exec.bin_seconds").observe(dt)
         except Exception as e:  # noqa: BLE001
             dt = time.perf_counter() - t0
             err = f"{type(e).__name__}: {e}"

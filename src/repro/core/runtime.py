@@ -61,6 +61,7 @@ import numpy as np
 
 from ..forecast.features import (FeatureSpec, align_delta, bucket_n,
                                  edge_pad, fleet_window, note_trace)
+from ..obs.trace import get_tracer
 from ..timeseries.transforms import DAY, calendar_features, regular_grid
 
 #: jitted ring updates / assemblies, keyed by static config (shapes key
@@ -268,31 +269,33 @@ class FleetRuntime:
                  ) -> Optional[_BinState]:
         """Watermark-delta poll: one O(log n + delta) store read, one
         jitted ring update. Returns None when a late append invalidates."""
-        raw, prior = self.system.store.read_many(
-            state.ids, end=now, since=state.t_hi, prior_counts=True)
-        if not np.array_equal(prior, state.prior):
-            return None                 # out-of-order append behind watermark
-        vals, mask = align_delta(raw, state.t_hi, now, state.spec.step)
-        pad = state.n_pad - state.n
-        vals32 = edge_pad(vals.astype(np.float32), pad)
-        mask_p = edge_pad(mask, pad)
-        if state.spec.use_weather:      # observed temps at the d new steps
-            tnew = state.sites.temperature(
-                state.t_hi + state.spec.step * np.arange(d))
-            tnew = edge_pad(tnew.astype(np.float32), pad)
-        else:
-            tnew = np.zeros((state.n_pad, d), np.float32)
-        warm_s = max(state.spec.target_lags, state.spec.weather_lags) + 1
-        upd = _cached_program(_UPDATE_FNS, (d, state.T, warm_s),
-                              partial(_make_update, d, state.T, warm_s))
-        (state.ring, state.filled, state.ring_t, state.y_win,
-         state.y_tail, state.t_tail) = upd(
-            state.ring, state.filled, state.ring_t, vals32, mask_p, tnew)
-        state.prior = prior + np.asarray([t.size for t, _ in raw], np.int64)
-        state.t0, state.t_hi = t0, now
-        state.targets_host = state.temps_host = None   # cold-build only
-        self.warm_loads += 1
-        return state
+        with get_tracer().span("runtime.advance"):
+            raw, prior = self.system.store.read_many(
+                state.ids, end=now, since=state.t_hi, prior_counts=True)
+            if not np.array_equal(prior, state.prior):
+                return None         # out-of-order append behind watermark
+            vals, mask = align_delta(raw, state.t_hi, now, state.spec.step)
+            pad = state.n_pad - state.n
+            vals32 = edge_pad(vals.astype(np.float32), pad)
+            mask_p = edge_pad(mask, pad)
+            if state.spec.use_weather:      # observed temps at the d new steps
+                tnew = state.sites.temperature(
+                    state.t_hi + state.spec.step * np.arange(d))
+                tnew = edge_pad(tnew.astype(np.float32), pad)
+            else:
+                tnew = np.zeros((state.n_pad, d), np.float32)
+            warm_s = max(state.spec.target_lags, state.spec.weather_lags) + 1
+            upd = _cached_program(_UPDATE_FNS, (d, state.T, warm_s),
+                                  partial(_make_update, d, state.T, warm_s))
+            (state.ring, state.filled, state.ring_t, state.y_win,
+             state.y_tail, state.t_tail) = upd(
+                state.ring, state.filled, state.ring_t, vals32, mask_p, tnew)
+            state.prior = prior + np.asarray([t.size for t, _ in raw],
+                                             np.int64)
+            state.t0, state.t_hi = t0, now
+            state.targets_host = state.temps_host = None   # cold-build only
+            self.warm_loads += 1
+            return state
 
     def _build(self, key, ids, instances, spec: FeatureSpec, t0: float,
                now: float, T: int) -> _BinState:
@@ -300,7 +303,6 @@ class FleetRuntime:
         path issues) plus one vectorized observed-temperature call;
         host-aligned rows kept in f64 for the cold train path, rings
         uploaded once."""
-        from ..obs.trace import get_tracer
         with get_tracer().span("runtime.build", n=len(ids)):
             return self._build_inner(key, ids, instances, spec, t0, now, T)
 

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..core.registry import ModelInterface
+from ..obs.trace import get_tracer
 from ..timeseries.transforms import DAY, HOUR, calendar_phases
 from .features import (FeatureSpec, bucket_n, design_matrix, edge_pad,
                        fleet_hourly_series, make_device_rollout,
@@ -284,8 +285,9 @@ class ForecastModelBase(ModelInterface):
         """Zip per-instance quantile bands onto ``(times, values)`` fleet
         results — shared by the device-runtime and cold scoring paths so
         both return the same 4-tuple shape."""
-        return [(t, v, *prediction_bands(m, v))
-                for m, (t, v) in zip(model_objects, results)]
+        with get_tracer().span("score.bands"):
+            return [(t, v, *prediction_bands(m, v))
+                    for m, (t, v) in zip(model_objects, results)]
 
     @classmethod
     def fleet_score(cls, instances: List[ModelInterface], model_objects, *,
@@ -374,22 +376,30 @@ class ForecastModelBase(ModelInterface):
                 return None
             fn = _ROLLOUT_CACHE.put(
                 key, make_device_rollout(predict, spec, H, mesh=mesh))
-        tl, wl = spec.target_lags, spec.weather_lags
-        f32 = jnp.float32
-        y0 = jnp.asarray(y_hist, f32)[..., -tl:]
-        if spec.use_weather:
-            tw0 = jnp.asarray(temp_hist, f32)[..., -(wl + 1):]
-        else:                            # unused carry, keep it minimal
-            tw0 = jnp.zeros(y0.shape[:-1] + (1,), f32)
-        hod, dow = calendar_phases(t_start + spec.step * np.arange(H))
-        # shape-bucketed dispatch: pad the instance axis to its bucket so
-        # nearby bin sizes hit ONE compilation (per-instance recursion =>
-        # padded lanes cannot perturb real ones); slice the pad back off
-        n = y0.shape[0] if y0.ndim > 1 else 0
-        pad = bucket_n(n) - n if n else 0
-        stacked = {k: edge_pad(jnp.asarray(v), pad) for k, v in stacked.items()}
-        args = [edge_pad(jnp.asarray(a, f32), pad)
-                for a in (mu, sd, y0, tw0, temps_future)]
-        out = fn(stacked, *args, jnp.asarray(hod, f32), jnp.asarray(dow, f32))
-        out = np.asarray(out, np.float64)
+        tracer = get_tracer()
+        # the dispatch with its input staging; device.wait is the host
+        # blocked on the chip
+        with tracer.span("score.rollout"):
+            tl, wl = spec.target_lags, spec.weather_lags
+            f32 = jnp.float32
+            y0 = jnp.asarray(y_hist, f32)[..., -tl:]
+            if spec.use_weather:
+                tw0 = jnp.asarray(temp_hist, f32)[..., -(wl + 1):]
+            else:                        # unused carry, keep it minimal
+                tw0 = jnp.zeros(y0.shape[:-1] + (1,), f32)
+            hod, dow = calendar_phases(t_start + spec.step * np.arange(H))
+            # shape-bucketed dispatch: pad the instance axis to its bucket
+            # so nearby bin sizes hit ONE compilation (per-instance
+            # recursion => padded lanes cannot perturb real ones); slice
+            # the pad back off
+            n = y0.shape[0] if y0.ndim > 1 else 0
+            pad = bucket_n(n) - n if n else 0
+            stacked = {k: edge_pad(jnp.asarray(v), pad)
+                       for k, v in stacked.items()}
+            args = [edge_pad(jnp.asarray(a, f32), pad)
+                    for a in (mu, sd, y0, tw0, temps_future)]
+            out = fn(stacked, *args, jnp.asarray(hod, f32),
+                     jnp.asarray(dow, f32))
+            with tracer.span("device.wait"):
+                out = np.asarray(out, np.float64)
         return out[:n] if n else out

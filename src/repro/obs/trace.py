@@ -19,6 +19,15 @@ Design constraints (ISSUE 10):
   ``finished - len(buf)``.
 - **Cheap when off.** ``span()`` on a disabled tracer returns one
   shared no-op context manager: no allocation, two attribute loads.
+- **On the profiler's clock.** While a ``jax.profiler`` trace is
+  recording, every ``span()`` also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name on the same thread,
+  so the span lands in the trace's host plane on the device timeline's
+  clock (its args stay in the ring only). With no trace recording the
+  only cost is one ``TraceAnnotation.is_enabled()`` check; jax is looked
+  up only once some other module has imported it, so ``obs`` imports
+  without jax. ``record()`` intervals (e.g. ``serverless.invoke``) are
+  measured after the fact and stay host-only.
 
 Cross-process stitching: the invoker puts ``current()`` —
 ``{"trace_id", "parent_id"}`` — on the JSON invocation payload; the
@@ -34,6 +43,7 @@ memory.
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from collections import deque
@@ -105,10 +115,31 @@ class _NullCtx:
 
 _NULL_CTX = _NullCtx()
 
+#: ``jax.profiler.TraceAnnotation``, once jax has been imported
+_ANNOTATION = None
+
+
+def _profiler_annotation(name: str):
+    """An entered ``TraceAnnotation(name)`` while a profiler trace is
+    recording, else None. Before anything imports jax no trace can be
+    recording, and jax is not imported from here."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        ann = _ANNOTATION = TraceAnnotation
+    if not ann.is_enabled():
+        return None
+    a = ann(name)
+    a.__enter__()
+    return a
+
 
 class _SpanCtx:
     __slots__ = ("tracer", "name", "args", "trace_id", "span_id",
-                 "parent_id", "remote", "t0")
+                 "parent_id", "remote", "t0", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self.tracer = tracer
@@ -129,12 +160,15 @@ class _SpanCtx:
             self.remote = False
         self.span_id = tr._next_id()
         stack.append((self.trace_id, self.span_id, False))
+        self.annotation = _profiler_annotation(self.name)
         self.t0 = tr.clock()
         return self
 
     def __exit__(self, *exc):
         tr = self.tracer
         t1 = tr.clock()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         tr._stack().pop()
         tr._finish(Span(self.trace_id, self.span_id, self.parent_id,
                         self.name, self.t0, t1,
@@ -218,7 +252,8 @@ class Tracer:
                args: Optional[dict] = None) -> int:
         """Append an interval measured outside a ``with`` block (e.g. a
         serverless invocation whose dispatch and settle happen on
-        different control-flow legs). ``span_id`` may be pre-allocated
+        different control-flow legs). Host-only: it never reaches a
+        profiler trace. ``span_id`` may be pre-allocated
         via ``allocate_id`` so children created elsewhere (a worker
         process) can parent under it before it is recorded."""
         if not self.enabled:

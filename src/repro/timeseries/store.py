@@ -121,6 +121,8 @@ class TimeSeriesStore:
         self.compaction_count = 0      # tail flushes
         self.merge_count = 0           # segment merges
         self.merged_points = 0         # points moved by merges
+        # points concatenated and sorted by tail rebuilds (_tail_segment)
+        self.tail_sort_points = 0
         self.journal = None            # durability.Journal when Castor.open'd
 
     # ---------------- write path ----------------
@@ -254,6 +256,7 @@ class TimeSeriesStore:
             t = np.concatenate(s.tail_t) if len(s.tail_t) > 1 else s.tail_t[0]
             v = np.concatenate(s.tail_v) if len(s.tail_v) > 1 else s.tail_v[0]
             order = np.argsort(t, kind="stable")
+            self.tail_sort_points += t.size
             s.tail_view = _Segment(_freeze(t[order]), _freeze(v[order]))
         return s.tail_view
 
@@ -337,10 +340,13 @@ class TimeSeriesStore:
         if not tracer.enabled:
             return self._read_many(ts_ids, start, end, since=since,
                                    prior_counts=prior_counts)
+        k0 = self.tail_sort_points
         with tracer.span("store.read_many", n=len(ts_ids),
-                         delta=since is not None):
-            return self._read_many(ts_ids, start, end, since=since,
-                                   prior_counts=prior_counts)
+                         delta=since is not None) as sp:
+            out = self._read_many(ts_ids, start, end, since=since,
+                                  prior_counts=prior_counts)
+            sp.set(tail_points=self.tail_sort_points - k0)
+            return out
 
     def _read_many(self, ts_ids: Sequence[str],
                    start: Optional[float] = None,
@@ -396,9 +402,12 @@ class TimeSeriesStore:
         tracer = get_tracer()
         if not tracer.enabled:
             return self._read_many_flat(ts_ids, start, end, since=since)
+        k0 = self.tail_sort_points
         with tracer.span("store.read_many", n=len(ts_ids),
-                         delta=since is not None, flat=True):
-            return self._read_many_flat(ts_ids, start, end, since=since)
+                         delta=since is not None, flat=True) as sp:
+            out = self._read_many_flat(ts_ids, start, end, since=since)
+            sp.set(tail_points=self.tail_sort_points - k0)
+            return out
 
     def _read_many_flat(self, ts_ids: Sequence[str],
                         start: Optional[float] = None,

@@ -427,3 +427,94 @@ def test_retrace_counters_named_per_program():
     assert trace_count() - before_total == 2       # legacy delta intact
     assert retrace_counts()["test_prog"] - before == 2
     assert get_metrics().counter("jit.retrace.test_prog").value >= 2
+
+
+# ------------------------------------------------ the profiler's clock
+def _host_events(trace_dir):
+    """``{name: (start_ns, end_ns, line)}`` of the obs-style events in the
+    host planes of the one xplane under ``trace_dir``."""
+    import pathlib
+
+    from jax.profiler import ProfileData
+    (path,) = pathlib.Path(trace_dir).rglob("*.xplane.pb")
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for k, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("obs_test."):
+                        out[e.name] = (e.start_ns, e.end_ns,
+                                       (plane.name, k))
+    return out
+
+
+def test_spans_land_in_a_recording_profiler_trace(tmp_path):
+    import jax
+
+    tr = Tracer(capacity=64)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("obs_test.tick", now=1.0):
+            with tr.span("obs_test.bin", jobs=3):
+                with tr.span("obs_test.write"):
+                    jax.numpy.ones(4).block_until_ready()
+            with tr.span("obs_test.commit"):
+                pass
+    ev = _host_events(tmp_path)
+    assert set(ev) == {"obs_test.tick", "obs_test.bin", "obs_test.write",
+                       "obs_test.commit"}      # by name alone, no args
+    assert len({line for _, _, line in ev.values()}) == 1   # one thread
+    ring = {s.name: s for s in tr.spans()}
+    by_id = {s.span_id: s.name for s in tr.spans()}
+    for name, s in ring.items():
+        if s.parent_id:                        # nested as in the ring
+            a, b, _ = ev[name]
+            pa, pb, _ = ev[by_id[s.parent_id]]
+            assert pa <= a <= b <= pb, name
+
+
+def test_no_annotation_is_made_while_no_trace_records(monkeypatch,
+                                                      tmp_path):
+    import jax
+    import repro.obs.trace as obs_trace
+    from jax.profiler import TraceAnnotation
+
+    made = []
+
+    class Counting(TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", Counting)
+    tr = Tracer(capacity=64)
+    for _ in range(3):
+        with tr.span("obs_test.off"):
+            pass
+    assert made == [] and tr.finished == 3
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("obs_test.on"):
+            pass
+    assert made == ["obs_test.on"]
+
+
+def test_read_many_counts_the_tail_points_it_sorts(tracer):
+    """``tail_points`` on ``store.read_many`` counts the points of each
+    tail rebuilt for the call: the sum of the unflushed tails' sizes, and
+    0 when the cached sorted tails are read again with no append."""
+    import numpy as np
+
+    from repro.timeseries.store import TimeSeriesStore
+    st_ = TimeSeriesStore(tail_max=1024)
+    st_.append("a", np.arange(100.0), np.ones(100))
+    st_.compact()                              # 100 points out of the tail
+    for lo in (300.0, 100.0, 200.0):           # three unsorted chunks
+        st_.append("a", lo + np.arange(10.0), np.ones(10))
+    st_.append("b", np.arange(7.0)[::-1], np.ones(7))
+    for _ in range(2):
+        st_.read_many(["a", "b", "missing"], since=50.0, prior_counts=True)
+        st_.read_many_flat(["a", "b"], since=50.0)
+    reads = [s for s in tracer.spans() if s.name == "store.read_many"]
+    assert [s.args["tail_points"] for s in reads] == [37, 0, 0, 0]
+    st_.append("b", [9.0], [1.0])
+    st_.read_many_flat(["a", "b"], since=50.0)
+    assert tracer.spans()[-1].args["tail_points"] == 8
