@@ -11,27 +11,35 @@ The seed implementation concatenated and re-sorted a series' entire append
 history on every ``read()`` (and even ``last_time()``), so read cost grew
 superlinearly with ingestion. This engine organizes each series as:
 
-* an unsorted **tail**: raw appended chunks, bounded by ``tail_max`` points;
+* a sorted **tail**: one contiguous run in growable buffers, bounded by
+  ``tail_max`` points, kept in time order as chunks land;
 * a list of sorted immutable **segments**: columnar ``(times, values)``
   pairs, each ascending in time, ordered oldest-to-newest by creation.
 
-Write path: ``append`` lands chunks in the tail in O(1). When the tail
-exceeds ``tail_max`` it is stable-sorted into a new segment (touching only
-the new points) and similar-sized segments are tiered-merged two at a time.
-A merge of two sorted runs is a single linear interleave (the searchsorted
-trick) — the full history is **never** re-sorted in one shot, and total
-ingest cost stays O(n log n) amortized with O(log n) live segments.
+Write path: a sorted chunk whose first time is at or after the tail's
+newest lands in the buffers' free capacity past the tail (one bounds check
+and one copy; the buffers grow geometrically, by copying into fresh ones).
+Any other chunk is stable-sorted and linearly merged with the tail into
+fresh buffers at append time, so no later read re-sorts it. When the tail
+reaches ``tail_max`` its run becomes a new segment as it is, with no sort,
+and similar-sized segments are tiered-merged two at a time. A merge of two
+sorted runs is a single linear interleave (the searchsorted trick) — the
+full history is **never** re-sorted in one shot, and total ingest cost
+stays O(n log n) amortized with O(log n) live segments.
 
-Read path: ``read``/``read_many`` binary-search every segment's window
-boundaries plus a cached sorted view of the tail, and linearly interleave
-only the returned window points — O(log n + k + dirty) for a k-point
-window, where *dirty* is the (usually tiny) data not yet in the oldest
-segment. When dirty data exceeds 1/8 of the series, the read first
-consolidates (flush tail, linear-merge segments to one) so the cost is
-amortized against the appends that created it; after that, reads are pure
-O(log n + k) slices until enough new appends arrive. Steady interleaved
-append/read workloads therefore never rewrite the full history per read.
-``last_time``/``first_time`` are O(1) (tracked incrementally on append).
+Read path: ``read``/``read_many`` binary-search every segment's and the
+tail's window boundaries and linearly interleave only the returned window
+points — O(log n + k + dirty) for a k-point window, where *dirty* is the
+(usually tiny) data not yet in the oldest segment. When dirty data exceeds
+1/8 of the series, the read first consolidates (flush tail, linear-merge
+segments to one) so the cost is amortized against the appends that
+created it; after that, reads are pure O(log n + k) slices until enough
+new appends arrive. A watermark-delta window that lies wholly in one run —
+inside the tail, once every segment ends before it, or in a series'
+single segment — is two binary searches and zero-copy views. Steady
+interleaved append/read workloads therefore never rewrite the full history
+per read. ``last_time``/``first_time`` are O(1) (tracked incrementally on
+append).
 
 Invariants (checked by ``tests/test_store.py``):
 
@@ -41,9 +49,10 @@ Invariants (checked by ``tests/test_store.py``):
    compaction — reads observe exactly the seed store's ordering);
 3. ``sum(segment sizes) + tail size == count`` — compaction moves points,
    it never drops or duplicates them;
-4. returned arrays are read-only views of immutable segment storage —
-   many parallel model executions share one columnar copy (copy before
-   mutating).
+4. returned arrays are read-only views of immutable storage — many
+   parallel model executions share one columnar copy (copy before
+   mutating); the tail's buffers are written only past the tail's
+   length, so a view handed out never changes.
 
 Concurrency: one lock per store guards both paths (appends are chunk-level,
 as in the paper's parallel-sender ingestion benchmark); reads may compact
@@ -90,19 +99,28 @@ class _Segment:
         return self.times.size
 
 
+_EMPTY = _freeze(np.empty(0, np.float64))
+
+
 @dataclass
 class _Series:
     segments: List[_Segment] = field(default_factory=list)
-    tail_t: List[np.ndarray] = field(default_factory=list)
-    tail_v: List[np.ndarray] = field(default_factory=list)
+    # the tail: one sorted run, tail_t[:tail_n] / tail_v[:tail_n]. Those
+    # are read-only views of the growable buffers buf_t / buf_v, which
+    # appends write only past tail_n.
+    tail_t: np.ndarray = field(default_factory=lambda: _EMPTY)
+    tail_v: np.ndarray = field(default_factory=lambda: _EMPTY)
+    buf_t: np.ndarray = field(default_factory=lambda: _EMPTY)
+    buf_v: np.ndarray = field(default_factory=lambda: _EMPTY)
     tail_n: int = 0
     count: int = 0
     t_min: float = math.inf
     t_max: float = -math.inf
-    tail_view: Optional[_Segment] = None    # cached sorted tail (ephemeral)
+    seg_t_max: float = -math.inf            # newest time in any segment
 
-
-_EMPTY = _freeze(np.empty(0, np.float64))
+    def set_buffers(self, bt: np.ndarray, bv: np.ndarray) -> None:
+        self.buf_t, self.buf_v = bt, bv
+        self.tail_t, self.tail_v = _freeze(bt.view()), _freeze(bv.view())
 
 
 class TimeSeriesStore:
@@ -121,8 +139,9 @@ class TimeSeriesStore:
         self.compaction_count = 0      # tail flushes
         self.merge_count = 0           # segment merges
         self.merged_points = 0         # points moved by merges
-        # points concatenated and sorted by tail rebuilds (_tail_segment)
-        self.tail_sort_points = 0
+        self.tail_merges = 0           # out-of-order chunks merged into tails
+        self.tail_sort_points = 0      # points those merges moved
+        self._tail_sorts_read = 0      # tail_sort_points at the last span
         self.journal = None            # durability.Journal when Castor.open'd
 
     # ---------------- write path ----------------
@@ -133,23 +152,48 @@ class TimeSeriesStore:
         if times.size == 0:
             return 0
         with self._lock:
-            s = self._data.setdefault(ts_id, _Series())
-            s.tail_t.append(times)
-            s.tail_v.append(values)
-            s.tail_n += times.size
-            s.tail_view = None
-            s.count += times.size
-            s.t_min = min(s.t_min, float(times.min()))
-            s.t_max = max(s.t_max, float(times.max()))
+            s = self._data.get(ts_id)
+            if s is None:
+                s = self._data[ts_id] = _Series()
+            self._append_locked(s, times, values)
             self.append_count += times.size
             j = self.journal
             if j is not None:      # one record per append call (atomic:
                 j.append("ts", {   # a chunk replays whole or not at all)
                     "id": ts_id, "t": times, "v": values})
-            if s.tail_n >= self.tail_max:
-                self._flush_tail(s)
-                self._tier_merge(s)
         return times.size
+
+    def _append_locked(self, s: _Series, t: np.ndarray, v: np.ndarray
+                       ) -> None:
+        """Land a non-empty chunk in the series' sorted tail."""
+        n, k = s.tail_n, t.size
+        need = n + k
+        if (k > 1 and not (t[1:] >= t[:-1]).all()) \
+                or (n and t[0] < s.tail_t[n - 1]):
+            # out of order: stable-sort the chunk and merge it behind the
+            # tail's equal times into fresh buffers, so no view handed out
+            # changes — what a stable sort of the tail's chunks would give
+            if k > 1:
+                order = np.argsort(t, kind="stable")
+                t, v = t[order], v[order]
+            s.set_buffers(*_merge_sorted(s.tail_t[:n], s.tail_v[:n], t, v))
+            self.tail_merges += 1
+            self.tail_sort_points += need
+        else:
+            bt, bv = s.buf_t, s.buf_v
+            if need > bt.size:      # double up to tail_max, in new buffers
+                cap = max(need, min(2 * bt.size, self.tail_max), 8)
+                bt, bv = np.empty(cap), np.empty(cap)
+                bt[:n], bv[:n] = s.buf_t[:n], s.buf_v[:n]
+                s.set_buffers(bt, bv)
+            bt[n:need], bv[n:need] = t, v
+        s.tail_n = need
+        s.count += k
+        s.t_min = min(s.t_min, float(t[0]))      # t is sorted by now
+        s.t_max = max(s.t_max, float(t[-1]))
+        if need >= self.tail_max:
+            self._flush_tail(s)
+            self._tier_merge(s)
 
     def append_points(self, ts_ids: Sequence[str], times, values) -> int:
         """Batched one-point-per-series append under ONE lock — the
@@ -168,14 +212,12 @@ class TimeSeriesStore:
         t = np.asarray(times, np.float64).ravel()
         v = np.asarray(values, np.float64).ravel()
         assert len(ts_ids) == t.size == v.size, (len(ts_ids), t.size, v.size)
-        t_list = t.tolist()                  # python floats: cheap compares
         # one C-loop view split per column instead of a python slice pair
         # per point (rows of the (n, 1) reshape are the same 1-element
         # float64 views t[k:k+1] would produce)
         rows_t = list(t.reshape(-1, 1))
         rows_v = list(v.reshape(-1, 1))
         data_get = self._data.get
-        tail_max = self.tail_max
         with self._lock:
             for k, ts_id in enumerate(ts_ids):
                 # get-then-create, not setdefault(_Series()): steady state
@@ -184,19 +226,7 @@ class TimeSeriesStore:
                 s = data_get(ts_id)
                 if s is None:
                     s = self._data[ts_id] = _Series()
-                s.tail_t.append(rows_t[k])
-                s.tail_v.append(rows_v[k])
-                s.tail_n += 1
-                s.tail_view = None
-                s.count += 1
-                tk = t_list[k]
-                if tk < s.t_min:
-                    s.t_min = tk
-                if tk > s.t_max:
-                    s.t_max = tk
-                if s.tail_n >= tail_max:
-                    self._flush_tail(s)
-                    self._tier_merge(s)
+                self._append_locked(s, rows_t[k], rows_v[k])
             self.append_count += t.size
             j = self.journal
             if j is not None:      # whole batch = one atomic record (the
@@ -206,12 +236,20 @@ class TimeSeriesStore:
         return int(t.size)
 
     def _flush_tail(self, s: _Series) -> None:
-        """Promote the sorted tail view to a new immutable segment."""
-        if not s.tail_n:
+        """Promote the tail's sorted run to a new immutable segment as it
+        is, trimmed when its buffers hold more than twice its points; the
+        next tail starts in new buffers."""
+        n = s.tail_n
+        if not n:
             return
-        s.segments.append(self._tail_segment(s))   # reuses the cached sort
-        s.tail_t, s.tail_v, s.tail_n = [], [], 0
-        s.tail_view = None
+        if s.buf_t.size > 2 * n:
+            t, v = _freeze(s.buf_t[:n].copy()), _freeze(s.buf_v[:n].copy())
+        else:
+            t, v = s.tail_t[:n], s.tail_v[:n]
+        s.segments.append(_Segment(t, v))
+        s.seg_t_max = max(s.seg_t_max, float(t[-1]))
+        s.tail_t = s.tail_v = s.buf_t = s.buf_v = _EMPTY
+        s.tail_n = 0
         self.compaction_count += 1
 
     def _tier_merge(self, s: _Series) -> None:
@@ -250,19 +288,36 @@ class TimeSeriesStore:
                 self._consolidate(s)
 
     # ---------------- read path ----------------
-    def _tail_segment(self, s: _Series) -> _Segment:
-        """Sorted view of the tail, cached until the next append."""
-        if s.tail_view is None:
-            t = np.concatenate(s.tail_t) if len(s.tail_t) > 1 else s.tail_t[0]
-            v = np.concatenate(s.tail_v) if len(s.tail_v) > 1 else s.tail_v[0]
-            order = np.argsort(t, kind="stable")
-            self.tail_sort_points += t.size
-            s.tail_view = _Segment(_freeze(t[order]), _freeze(v[order]))
-        return s.tail_view
+    @staticmethod
+    def _window_run(s: _Series, start
+                    ) -> Optional[Tuple[np.ndarray, np.ndarray, int]]:
+        """The one sorted run that holds every point of ``s`` at or after
+        ``start`` — the tail once every segment ends before ``start``, or
+        the only segment of a series with no tail — and the count of
+        points before that run; None when no single run does."""
+        n = s.tail_n
+        if n:
+            if s.seg_t_max < start:
+                return s.tail_t[:n], s.tail_v[:n], s.count - n
+        elif len(s.segments) == 1:
+            seg = s.segments[0]
+            return seg.times, seg.values, 0
+        return None
+
+    def _tail_sorts_since_read(self) -> int:
+        """``tail_sort_points`` gained since the previous traced read (a
+        ``read_many`` span's ``tail_points``): the points that out-of-order
+        appends merged into tails, store-wide, since then — the sort work
+        a read once did itself."""
+        with self._lock:
+            k, self._tail_sorts_read = (
+                self.tail_sort_points - self._tail_sorts_read,
+                self.tail_sort_points)
+        return k
 
     def _prior_count_locked(self, s: Optional[_Series], t) -> int:
         """Number of stored points with time < ``t`` — O(log n) binary
-        searches over the sorted segments plus the cached sorted tail.
+        searches over the sorted segments and the sorted tail.
         This is the late-data watermark check for delta readers: a count
         that moved under an unchanged watermark means an out-of-order
         append landed in already-consumed history."""
@@ -270,7 +325,7 @@ class TimeSeriesStore:
             return 0
         n = sum(int(np.searchsorted(seg.times, t)) for seg in s.segments)
         if s.tail_n:
-            n += int(np.searchsorted(self._tail_segment(s).times, t))
+            n += int(s.tail_t[:s.tail_n].searchsorted(t))
         return n
 
     def _read_locked(self, s: Optional[_Series], start, end,
@@ -291,7 +346,7 @@ class TimeSeriesStore:
             self._consolidate(s)
         segs = list(s.segments)
         if s.tail_n:
-            segs.append(self._tail_segment(s))   # newest run: append order
+            segs.append(_Segment(s.tail_t[:s.tail_n], s.tail_v[:s.tail_n]))
         parts: List[Tuple[np.ndarray, np.ndarray]] = []
         for seg in segs:
             lo = 0 if start is None else int(np.searchsorted(seg.times, start))
@@ -340,12 +395,11 @@ class TimeSeriesStore:
         if not tracer.enabled:
             return self._read_many(ts_ids, start, end, since=since,
                                    prior_counts=prior_counts)
-        k0 = self.tail_sort_points
         with tracer.span("store.read_many", n=len(ts_ids),
                          delta=since is not None) as sp:
             out = self._read_many(ts_ids, start, end, since=since,
                                   prior_counts=prior_counts)
-            sp.set(tail_points=self.tail_sort_points - k0)
+            sp.set(tail_points=self._tail_sorts_since_read())
             return out
 
     def _read_many(self, ts_ids: Sequence[str],
@@ -358,6 +412,7 @@ class TimeSeriesStore:
             start = since
         consolidate = not fast
         data_get = self._data.get
+        window_run = self._window_run
         with self._lock:
             self.read_many_count += 1
             if fast:
@@ -365,19 +420,19 @@ class TimeSeriesStore:
             out, prior = [], []
             for i in ts_ids:
                 s = data_get(i)
-                if fast and s is not None and s.count \
-                        and len(s.segments) == 1 and not s.tail_n:
-                    # steady-state fast path: consolidated series, delta
-                    # window — two binary searches, zero-copy views
+                run = window_run(s, start) if fast and s is not None \
+                    else None
+                if run is not None:
+                    # steady-state fast path: the delta window lies in one
+                    # sorted run — two binary searches, zero-copy views
                     # (ndarray.searchsorted directly: the np.searchsorted
                     # dispatch wrapper is measurable at fleet width)
-                    seg = s.segments[0]
-                    lo = seg.times.searchsorted(start)
-                    hi = seg.n if end is None else \
-                        seg.times.searchsorted(end)
+                    rt, rv, before = run
+                    lo = rt.searchsorted(start)
+                    hi = rt.size if end is None else rt.searchsorted(end)
                     if prior_counts:
-                        prior.append(int(lo))
-                    out.append((seg.times[lo:hi], seg.values[lo:hi]))
+                        prior.append(before + int(lo))
+                    out.append((rt[lo:hi], rv[lo:hi]))
                     continue
                 if prior_counts:
                     prior.append(self._prior_count_locked(s, start))
@@ -402,11 +457,10 @@ class TimeSeriesStore:
         tracer = get_tracer()
         if not tracer.enabled:
             return self._read_many_flat(ts_ids, start, end, since=since)
-        k0 = self.tail_sort_points
         with tracer.span("store.read_many", n=len(ts_ids),
                          delta=since is not None, flat=True) as sp:
             out = self._read_many_flat(ts_ids, start, end, since=since)
-            sp.set(tail_points=self.tail_sort_points - k0)
+            sp.set(tail_points=self._tail_sorts_since_read())
             return out
 
     def _read_many_flat(self, ts_ids: Sequence[str],
@@ -419,6 +473,7 @@ class TimeSeriesStore:
             start = since
         consolidate = not fast
         data_get = self._data.get
+        window_run = self._window_run
         no_end = end is None
         parts_t: List[np.ndarray] = []
         parts_v: List[np.ndarray] = []
@@ -431,16 +486,16 @@ class TimeSeriesStore:
                 self.delta_read_count += 1
             for i in ts_ids:
                 s = data_get(i)
-                if fast and s is not None and s.count \
-                        and len(s.segments) == 1 and not s.tail_n:
-                    seg = s.segments[0]
-                    st = seg.times
-                    lo = st.searchsorted(start)
-                    hi = seg.n if no_end else st.searchsorted(end)
+                run = window_run(s, start) if fast and s is not None \
+                    else None
+                if run is not None:
+                    rt, rv, _ = run
+                    lo = rt.searchsorted(start)
+                    hi = rt.size if no_end else rt.searchsorted(end)
                     if hi > lo:
                         sz_append(hi - lo)
-                        pt_append(st[lo:hi])
-                        pv_append(seg.values[lo:hi])
+                        pt_append(rt[lo:hi])
+                        pv_append(rv[lo:hi])
                     else:
                         sz_append(0)
                     continue
@@ -513,6 +568,7 @@ class TimeSeriesStore:
                 "compactions": self.compaction_count,
                 "merges": self.merge_count,
                 "merged_points": self.merged_points,
+                "tail_merges": self.tail_merges,
             }
 
     # ---------------- persistence ----------------

@@ -498,9 +498,10 @@ def test_no_annotation_is_made_while_no_trace_records(monkeypatch,
 
 
 def test_read_many_counts_the_tail_points_it_sorts(tracer):
-    """``tail_points`` on ``store.read_many`` counts the points of each
-    tail rebuilt for the call: the sum of the unflushed tails' sizes, and
-    0 when the cached sorted tails are read again with no append."""
+    """``tail_points`` on ``store.read_many`` counts the tail points
+    sorted since the previous traced read: out-of-order chunks are merged
+    into the sorted tail when they land, and a read sorts nothing, so the
+    count is 0 when no such chunk landed in between."""
     import numpy as np
 
     from repro.timeseries.store import TimeSeriesStore
@@ -514,7 +515,13 @@ def test_read_many_counts_the_tail_points_it_sorts(tracer):
         st_.read_many(["a", "b", "missing"], since=50.0, prior_counts=True)
         st_.read_many_flat(["a", "b"], since=50.0)
     reads = [s for s in tracer.spans() if s.name == "store.read_many"]
-    assert [s.args["tail_points"] for s in reads] == [37, 0, 0, 0]
-    st_.append("b", [9.0], [1.0])
+    # two chunks behind a's tail (20 + 30 points moved), b's unsorted one (7)
+    assert [s.args["tail_points"] for s in reads] == [57, 0, 0, 0]
+    assert st_.tail_sort_points == 57 and st_.tail_merges == 3
+    st_.append("b", [9.0], [1.0])              # in order: no merge
     st_.read_many_flat(["a", "b"], since=50.0)
-    assert tracer.spans()[-1].args["tail_points"] == 8
+    assert tracer.spans()[-1].args["tail_points"] == 0
+    st_.append("b", [3.0], [1.0])              # behind b's tail of 8
+    st_.read_many_flat(["a", "b"], since=50.0)
+    assert tracer.spans()[-1].args["tail_points"] == 9
+    assert st_.tail_sort_points == 66 and st_.tail_merges == 4

@@ -112,6 +112,148 @@ def test_randomized_interleaved_append_read_matches_reference():
     assert st.length("x") == sum(len(b[0]) for b in batches)
 
 
+# ---------------- the sorted tail ----------------
+#: chunk kinds of the oracle test; "late" ones take the out-of-order merge
+IN_ORDER = ("single", "multi", "equal", "empty")
+LATE = ("unsorted", "behind_tail", "behind_segment")
+
+
+def _chunk(rng, kind, newest):
+    """A chunk of ``kind`` against the newest time stored so far, on a
+    half-hour grid so equal timestamps are common."""
+    n = int(rng.integers(2, 7))
+    step = rng.integers(0, 3, n) * 0.5
+    if kind == "single":
+        return np.array([newest + 0.5 * int(rng.integers(0, 3))])
+    if kind == "multi":
+        return newest + np.cumsum(step)
+    if kind == "equal":
+        return np.full(n, newest)
+    if kind == "empty":
+        return np.empty(0)
+    if kind == "unsorted":
+        return rng.permutation(newest + np.cumsum(step + 0.5))
+    if kind == "behind_tail":               # just behind the newest points
+        return np.sort(newest - rng.integers(0, 4, n) * 0.5)
+    return rng.integers(0, 2 * max(int(newest), 1), n) * 0.5   # anywhere
+
+
+@pytest.mark.parametrize("tail_max", [3, 16])
+@pytest.mark.parametrize("mix", ["in_order", "late"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sorted_tail_reads_match_a_stable_sort_of_the_history(
+        seed, mix, tail_max):
+    """Random append sequences through one store: after every append each
+    read form returns, bit for bit, the stable sort of the whole append
+    history sliced, read-only; arrays handed out earlier never change."""
+    rng = np.random.default_rng([seed, tail_max])
+    kinds = IN_ORDER + (LATE if mix == "late" else ())
+    st = TimeSeriesStore(tail_max=tail_max)
+    hist_t, hist_v, handed = [], [], []
+    newest = 10.0
+    for step in range(80):
+        t = _chunk(rng, kinds[int(rng.integers(len(kinds)))], newest)
+        v = step * 100.0 + np.arange(t.size)     # unique: shows tie order
+        st.append("x", t, v)
+        hist_t.append(t)
+        hist_v.append(v)
+        newest = max(newest, float(t.max(initial=newest)))
+        ot = np.concatenate(hist_t)
+        order = np.argsort(ot, kind="stable")
+        ot, ov = ot[order], np.concatenate(hist_v)[order]
+        if not ot.size:
+            continue
+        since = float(rng.choice([newest, newest - 0.5, ot[-1] + 1.0,
+                                  rng.choice(ot), ot[0] - 1.0]))
+        lo, hi = sorted(float(x) for x in rng.choice(ot, 2))
+        k = np.searchsorted(ot, since)
+        a, b = np.searchsorted(ot, lo), np.searchsorted(ot, hi)
+        # delta reads first: they never consolidate, so they meet tails
+        # beside several segments
+        (pair, _), prior = st.read_many(["x", "missing"], since=since,
+                                        prior_counts=True)
+        assert prior.tolist() == [k, 0]
+        sizes, ft, fv = st.read_many_flat(["missing", "x"], since=since)
+        assert sizes.tolist() == [0, ot.size - k]
+        got = [pair, (ft, fv), st.read_many(["x"], lo, hi)[0],
+               st.read("x", lo, hi), st.read("x")]
+        want = [(ot[k:], ov[k:])] * 2 + [(ot[a:b], ov[a:b])] * 2 + [(ot, ov)]
+        for (gt, gv), (wt, wv) in zip(got, want):
+            np.testing.assert_array_equal(gt, wt)
+            np.testing.assert_array_equal(gv, wv)
+            # the flat form concatenates: its arrays are the caller's own
+            assert gt is ft or not (gt.flags.writeable or gv.flags.writeable)
+        handed += [(gt, gt.copy()) for gt, _ in got] \
+            + [(gv, gv.copy()) for _, gv in got]
+        _check_invariants(st, "x")
+        s = st._data["x"]
+        assert np.all(np.diff(s.tail_t[:s.tail_n]) >= 0)
+    st.append("x", [newest + 1.0], [-1.0])
+    st.compact()                                 # a flush and merges
+    assert st.compaction_count and st.merge_count
+    for arr, copy in handed:
+        np.testing.assert_array_equal(arr, copy)
+    assert (st.tail_merges > 0) == (mix == "late")
+
+
+def test_tail_merges_count_only_out_of_order_chunks():
+    """Hourly in-order appends with delta reads in between sort nothing,
+    and the reads' spans carry ``tail_points=0``. One chunk behind the tail
+    merges the tail and its points once, and the next read's span counts
+    them."""
+    from repro.obs.trace import Tracer, set_tracer
+    tr = Tracer(capacity=256)
+    prev = set_tracer(tr)
+    try:
+        st = TimeSeriesStore(tail_max=64)
+        ids = ["a", "b"]
+        for i in ids:
+            st.append(i, np.arange(0.0, 40 * HOUR, HOUR), np.ones(40))
+            st.compact(i)
+        for h in range(40, 50):
+            for i in ids:
+                st.append(i, [h * HOUR + 0.05 * HOUR], [float(h)])
+            raw, prior = st.read_many(ids, since=h * HOUR,
+                                      prior_counts=True)
+            assert [t.size for t, _ in raw] == [1, 1]
+            assert prior.tolist() == [h, h]
+        assert st.tail_sort_points == 0 and st.stats()["tail_merges"] == 0
+        reads = [s for s in tr.spans() if s.name == "store.read_many"]
+        assert [s.args["tail_points"] for s in reads] == [0] * 10
+        st.append("a", [45.5 * HOUR, 44.5 * HOUR], [0.0, 0.0])
+        assert st.tail_sort_points == 10 + 2
+        assert st.stats()["tail_merges"] == 1
+        st.read_many_flat(ids, since=40 * HOUR)
+        assert tr.spans()[-1].args["tail_points"] == 12
+    finally:
+        set_tracer(prev)
+
+
+
+@pytest.mark.parametrize("flat", [False, True], ids=["pairs", "flat"])
+def test_read_span_counts_tail_sorts_since_the_previous_traced_read(flat):
+    """An untraced read leaves the merges it follows to the next traced
+    read, which counts every series' merges once, whichever ids it reads."""
+    from repro.obs.trace import Tracer, set_tracer
+    tr = Tracer(capacity=64)
+    prev = set_tracer(tr)
+    try:
+        st = TimeSeriesStore()
+        read = st.read_many_flat if flat else st.read_many
+        st.append("a", [5.0, 6.0], [0.0, 0.0])
+        st.append("a", [1.0], [0.0])              # behind the tail: 3 moved
+        tr.enabled = False
+        read(["a"], since=0.0)
+        tr.enabled = True
+        st.append("b", [2.0, 1.0], [0.0, 0.0])    # unsorted: 2 moved
+        read(["a"], since=0.0)
+        read(["a"], since=0.0)
+        reads = [s for s in tr.spans() if s.name == "store.read_many"]
+        assert [s.args["tail_points"] for s in reads] == [5, 0]
+        assert st.tail_sort_points == 5 and st.stats()["tail_merges"] == 2
+    finally:
+        set_tracer(prev)
+
 # ---------------- O(1) metadata ----------------
 def test_last_first_time_without_consolidation():
     st = TimeSeriesStore(tail_max=1 << 30)   # nothing ever compacts
