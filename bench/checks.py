@@ -7,19 +7,13 @@ forecasts of a sample of boundaries drawn from the seed, the last one
 always among them, are kept with the model versions that made them.
 ``compare`` then runs the float64 reference over the same traffic and
 returns each number with its limit from ``bench/limits/<cell>.json``:
-
-* ``unpersisted``: boundaries x deployments without their forecast, or
-  jobs that failed (limit 0);
-* ``forecast_gap``: the largest absolute gap, in kWh, between a
-  persisted forecast value and the reference's;
-* LR ``theta_gap``: the largest absolute gap between a persisted ridge
-  coefficient and the reference's own float64 solve.
+``unpersisted``, boundaries x deployments without their forecast or jobs
+that failed (limit 0), and the numbers of the configuration's model,
+which ``bench/models/<reference>.py`` ``compare`` computes from what
+``collect`` kept.
 
 ``decide`` turns them into ``correct``: every number at or under its
-limit. The LR reference recomputes everything from the traffic, the fit
-included. The ANN reference scores with the networks themselves: the
-benchmark's own copy where it made them from the seed, the persisted ones
-otherwise.
+limit.
 """
 from __future__ import annotations
 
@@ -27,11 +21,7 @@ from types import SimpleNamespace
 
 import numpy as np
 
-import reference as ref
 from traffic import HOUR
-
-#: deployments per block of the LR reference (bounds its memory)
-BLOCK = 512
 
 
 def sample_ticks(n: int, k: int, seed: int) -> list:
@@ -43,13 +33,13 @@ def sample_ticks(n: int, k: int, seed: int) -> list:
 
 
 def collect(run) -> dict:
-    c, spec = run.castor, ref.Spec(run.config["user_params"])
+    c, horizon = run.castor, int(run.config["user_params"]["horizon"])
     bounds = [t["boundary"] for t in run.ticks]
     picked = sample_ticks(len(bounds), int(run.traffic["check_ticks"]),
                           run.seed)
-    want_t = np.arange(spec.horizon) * HOUR
+    want_t = np.arange(horizon) * HOUR
     missing = 0
-    got = np.full((len(run.names), len(picked), spec.horizon), np.nan)
+    got = np.full((len(run.names), len(picked), horizon), np.nan)
     pick_of = {bounds[j]: i for i, j in enumerate(picked)}
     for d, name in enumerate(run.names):
         by_t = {fc.created_at: fc for fc in c.predictions.history(name)}
@@ -65,7 +55,7 @@ def collect(run) -> dict:
     else:               # the benchmark's own copy, not the store's
         versions = [[SimpleNamespace(trained_at=run.now0, params=m)]
                     * len(picked) for m in run.seeded]
-    return {"site": run.site, "spec": spec, "config": run.config,
+    return {"site": run.site, "config": run.config,
             "seed": run.seed, "boundaries": [bounds[j] for j in picked],
             "got": got, "missing": missing,
             "failed": sum(t["failed"] for t in run.ticks),
@@ -74,9 +64,8 @@ def collect(run) -> dict:
 
 def compare(found: dict, cell: dict) -> dict:
     """``{name: (value, limit)}`` of the program's run."""
-    kind = found["config"]["reference"]
     numbers = {"unpersisted": float(found["missing"] + found["failed"])}
-    numbers.update(COMPARE[kind](found))
+    numbers.update(cell["model"].compare(found))
     return with_limits(numbers, cell)
 
 
@@ -92,74 +81,6 @@ def decide(numbers: dict) -> bool:
     return all(v <= lim for v, lim in numbers.values())
 
 
-def _scored(found):
+def scored(found: dict) -> np.ndarray:
     """Sampled (deployment, boundary) pairs that have a forecast."""
     return ~np.isnan(found["got"][..., 0])
-
-
-def compare_lr(found: dict) -> dict:
-    site, spec, b = found["site"], found["spec"], found["boundaries"]
-    lam = float(found["config"]["ridge_lambda"])
-    versions, scored = found["versions"], _scored(found)
-    trained = sorted({v.trained_at for vs in versions for v in vs
-                      if v is not None})
-    theta_gap, fc_gap = 0.0, 0.0
-    for lo in range(0, site.n, BLOCK):
-        rows = np.arange(lo, min(site.n, lo + BLOCK))
-        ys, ts, fs = ref.score_inputs(site, spec, b, rows)
-        for at in trained:
-            Xs, y, mu, sd = ref.training_set(site, spec, at, rows)
-            theta = ref.ridge(Xs, y, lam)
-            want = ref.rollout(
-                lambda x: ref.lr_predict(theta, (x - mu[:, None])
-                                         / sd[:, None]),
-                spec, ys, ts, fs, b)
-            for i, d in enumerate(rows):
-                use = [s for s, v in enumerate(versions[d])
-                       if v is not None and v.trained_at == at]
-                if not use:
-                    continue
-                th = np.asarray(versions[d][use[0]].params["params"]
-                                ["theta"], np.float64)
-                theta_gap = max(theta_gap, float(np.abs(th - theta[i]).max()))
-                use = [s for s in use if scored[d, s]]
-                if use:
-                    fc_gap = max(fc_gap, float(np.abs(
-                        found["got"][d, use] - want[i, use]).max()))
-    return {"forecast_gap": fc_gap, "theta_gap": theta_gap}
-
-
-def compare_ann(found: dict) -> dict:
-    site, spec, b = found["site"], found["spec"], found["boundaries"]
-    scored = _scored(found)
-    ys, ts, fs = ref.score_inputs(site, spec, b)
-    fc_gap = 0.0
-    mus = {}
-    for d in range(site.n):
-        vs = found["versions"][d]
-        for v in {id(v): v for v in vs if v is not None}.values():
-            cols = [s for s in range(len(b))
-                    if vs[s] is v and scored[d, s]]
-            if not cols:
-                continue
-            key = (v.trained_at, d)
-            if key not in mus:
-                mus.update(_standardisation(site, spec, v.trained_at))
-            mu, sd = mus[key]
-            layers, y_scale = ref.ann_layers([v.params["params"]])
-            want = ref.rollout(
-                lambda x: ref.ann_predict(layers, y_scale, (x - mu) / sd),
-                spec, ys[d:d + 1, cols], ts[d:d + 1, cols],
-                fs[d:d + 1, cols], [b[s] for s in cols])
-            fc_gap = max(fc_gap, float(
-                np.abs(found["got"][d, cols] - want[0]).max()))
-    return {"forecast_gap": fc_gap}
-
-
-def _standardisation(site, spec, at: float) -> dict:
-    """``{(at, d): (mu, sd)}`` of every deployment's fit at ``at``."""
-    _, _, mu, sd = ref.training_set(site, spec, at)
-    return {(at, d): (mu[d], sd[d]) for d in range(site.n)}
-
-
-COMPARE = {"lr": compare_lr, "ann": compare_ann}

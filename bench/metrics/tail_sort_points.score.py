@@ -1,6 +1,10 @@
-"""Store reads: points concatenated and sorted to rebuild store tails,
-the ``tail_points`` of the window's ``store.read_many`` spans, per
-window tick."""
+"""Store reads: tail points that out-of-order appends merged, per window
+tick. A chunk that lands before its series' newest tail time is merged
+into the sorted tail, moving the tail's points; the store counts them
+(``tail_sort_points``) and each ``store.read_many`` span carries the
+count gained since the previous one as ``tail_points``. In-order appends
+and the reads themselves sort nothing, so a feed that lands in order
+reads 0."""
 
 
 def read(run):

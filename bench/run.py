@@ -5,8 +5,11 @@
 Run from the root of a checkout: ``BENCHMARK.json`` names the cell's
 configuration (``bench/configs/<config>.json``) and traffic
 (``bench/mixes/<traffic>.json``); the per-layer metrics are readers in
-``bench/metrics/<metric>.py`` and the limits of the correctness check are
-in ``bench/limits/<cell>.json``. Nothing here names a cell.
+``bench/metrics/<metric>.py``, the limits of the correctness check are
+in ``bench/limits/<cell>.json``, and what depends on the configuration's
+model (its comparison, its control, its versions made from the seed and
+its operations) is in ``bench/models/<reference>.py``. Nothing here names
+a cell or a model.
 
 Set-up builds the site from the seed and its history, each sensor's
 unflushed store tail at its own point of the flush cycle (so the window
@@ -31,7 +34,6 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
-import copy  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -60,9 +62,9 @@ def load_json(path: Path) -> dict:
 
 
 def load_cell(root: Path, name: str, bench: Path = BENCH) -> dict:
-    """The cell ``name`` with its configuration, traffic, limits and the
-    per-layer metrics that read it, all found by name from
-    ``BENCHMARK.json`` and the data files under ``bench``."""
+    """The cell ``name`` with its configuration, its model's module,
+    traffic, limits and the per-layer metrics that read it, all found by
+    name from ``BENCHMARK.json`` and the files under ``bench``."""
     spec = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -70,6 +72,7 @@ def load_cell(root: Path, name: str, bench: Path = BENCH) -> dict:
     cell = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     config = load_json(root / configs[cell["config"]]["file"])
+    kind = config["reference"]
     traffic = load_json(bench / "mixes" / f"{cell['traffic']}.json")
     limits = load_json(bench / "limits" / f"{name}.json")
     per_layer = [m for m in spec["per_layer"]
@@ -78,18 +81,24 @@ def load_cell(root: Path, name: str, bench: Path = BENCH) -> dict:
                   if name in m.get("workloads", [name])]
     return {"name": name, "bench": bench, "chips": int(cell["chips"]),
             "config": config,
+            "model": load_module(bench / "models" / f"{kind}.py",
+                                 "bench_model_" + kind),
             "traffic": traffic, "limits": limits, "per_layer": per_layer,
             "end_to_end": end_to_end}
 
 
-def load_reader(metric: str, bench: Path = BENCH):
-    """``read(run) -> float | None`` from ``bench/metrics/<metric>.py``."""
-    path = bench / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
+def load_module(path: Path, name: str):
+    """The Python file ``path``, imported as ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str, bench: Path = BENCH):
+    """``read(run) -> float | None`` from ``bench/metrics/<metric>.py``."""
+    return load_module(bench / "metrics" / f"{metric}.py",
+                       "bench_metric_" + metric.replace(".", "_")).read
 
 
 def peak_of(kind: str) -> dict:
@@ -128,6 +137,14 @@ class Run:
     def __init__(self, cell: dict, seed: int, *, require_chip: bool = True):
         self.cell, self.seed = cell, int(seed)
         self.config, self.traffic = cell["config"], cell["traffic"]
+        # a mix with "weights": "seed" scores versions the benchmark made
+        # from the seed, and schedules no training
+        seeded = self.traffic.get("weights") == "seed"
+        if seeded and not hasattr(cell["model"], "seed_versions"):
+            raise SystemExit(
+                f"bench: the mix {cell['name']!r} asks for seeded weights, "
+                f"and bench/models/{self.config['reference']}.py has no "
+                f"seed_versions(run)")
         if require_chip:
             devices_for(cell["chips"])
         from repro.core import Castor
@@ -153,9 +170,6 @@ class Run:
             h = tr.get(key)
             return 1e12 if h is None else float(h) * HOUR
 
-        # a mix with "weights": "seed" scores versions the benchmark made
-        # from the seed, and schedules no training
-        seeded = tr.get("weights") == "seed"
         c.deploy_for_all(package=pkg, signal="ENERGY_LOAD", name_prefix=pkg,
                          kind="PROSUMER",
                          train=None if seeded else Schedule(
@@ -163,15 +177,8 @@ class Run:
                          score=Schedule(self.now0, every("score_every_h")),
                          user_params=dict(cfg["user_params"]))
         self.names = [f"{pkg}-{nm}" for nm in self.site.names]
-        self.seeded = None
-        if seeded:
-            from weights import MODELS
-            self.seeded = MODELS[cfg["reference"]](cfg, self.site, self.seed,
-                                                   self.now0)
-            for name, m in zip(self.names, self.seeded):   # the program
-                c.versions.save(name, copy.deepcopy(m),        # gets a copy
-                                trained_at=self.now0,
-                                metadata={"source": "benchmark seed"})
+        # the benchmark's own copy of each deployment's seeded version
+        self.seeded = cell["model"].seed_versions(self) if seeded else None
         self.k = 0                      # boundaries ticked so far
         self.ticks = []                 # window ticks: dicts
 

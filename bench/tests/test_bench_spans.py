@@ -50,17 +50,38 @@ def test_each_bin_opens_each_layer_span_once(tiny_run):
     reads = [s for s in spans if s.name == "store.read_many"]
     assert [by_id[s.parent_id].name for s in reads] \
         == ["runtime.advance"] * len(bins)
-    # the set-up left every tail partly filled: the window's reads sort
-    assert sum(s.args["tail_points"] for s in reads) > 0
+    # every chunk of the feed lands in order: no read finds tail points
+    # merged since the previous one
+    assert sum(s.args["tail_points"] for s in reads) == 0
 
 
 def test_span_readers_report_a_cpu_window(tiny_run):
     for name in ("persist_ms.score", "prepare_ms.score",
-                 "device_wait_ms.score", "tail_sort_points.score"):
+                 "device_wait_ms.score"):
         v = bench.load_reader(name)(tiny_run)
         assert v is not None and v > 0, name
+    # an in-order feed merges no tail points, and the reader says so
+    assert bench.load_reader("tail_sort_points.score")(tiny_run) == 0.0
     # no trace was recorded: the device reader has nothing to read
     assert bench.load_reader("idle_unattributed_ms.score")(tiny_run) is None
+
+
+def test_an_out_of_order_append_shows_in_tail_sort_points():
+    """A reading that lands behind its series' newest tail time is merged
+    into the tail: the window's next read counts the points it moved."""
+    from traffic import HOUR
+    cell = bench.load_cell(ROOT, CELLS["lr"])
+    cell["traffic"]["site"].update(n_prosumers=6, n_feeders=2)
+    run = bench.Run(cell, 2 ** 31 + 17, require_chip=False)
+    run.setup()
+    store, ts_id = run.castor.store, run.site.ts_ids[-1]
+    tail = store._data[ts_id]
+    late = tail.tail_t[tail.tail_n - 1] - 1.5 * HOUR
+    store.append(ts_id, [late], [1.0])
+    assert store.tail_merges == 1
+    run.window(0.2)
+    got = bench.load_reader("tail_sort_points.score")(run)
+    assert got == float(store.tail_sort_points) / len(run.ticks) > 0
 
 
 @pytest.mark.parametrize("name", READERS)

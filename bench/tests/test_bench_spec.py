@@ -4,6 +4,7 @@ import json
 import re
 import shutil
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -81,7 +82,7 @@ def test_a_new_cell_takes_new_files_only(tmp_path, monkeypatch):
     its limits and a per-layer metric, and drive the new cell on the CPU
     without touching any existing file."""
     b = tmp_path / "bench"
-    for d in ("configs", "mixes", "limits", "metrics"):
+    for d in ("configs", "mixes", "limits", "metrics", "models"):
         shutil.copytree(ROOT / "bench" / d, b / d)
     cfg = json.loads((b / "configs" / "lr_ridge.json").read_text())
     cfg.update(name="lr_short", user_params={
@@ -120,8 +121,151 @@ def test_a_new_cell_takes_new_files_only(tmp_path, monkeypatch):
     assert bench.load_reader("ticks_seen", b)(run) == 3.0
 
 
+#: a model of a new reference kind: one ridge model made from the seed and
+#: shared by every deployment, compared with its float64 rollout
+SHARED_LR = '''
+import numpy as np
+
+import checks
+import reference as ref
+
+CALLS = []
+
+
+def _want(found, theta):
+    site, b = found["site"], found["boundaries"]
+    spec = ref.Spec(found["config"]["user_params"])
+    ys, ts, fs = ref.score_inputs(site, spec, b)
+    th = np.broadcast_to(theta, (site.n, theta.size))
+    return ref.rollout(lambda x: ref.lr_predict(th, x), spec, ys, ts, fs, b)
+
+
+def _theta(found):
+    return np.asarray(found["versions"][0][0].params["params"]["theta"],
+                      np.float64)
+
+
+def compare(found):
+    gap = np.abs(found["got"] - _want(found, _theta(found)))
+    return {"forecast_gap": float(gap[checks.scored(found)].max())}
+
+
+def control(found):
+    theta = _theta(found).astype(np.float16).astype(np.float64)
+    gap = np.abs(found["got"] - _want(found, theta))
+    return {"forecast_gap": float(gap[checks.scored(found)].max())}
+
+
+def score_flops(config, n):
+    spec = ref.Spec(config["user_params"])
+    return 2.0 * (spec.n_features + 1) * n * spec.horizon
+
+
+def seed_versions(run):
+    CALLS.append(run.seed)
+    f = ref.Spec(run.config["user_params"]).n_features
+    theta = np.random.default_rng(run.seed).normal(0.0, 0.01, f + 1)
+    theta[0], theta[-1] = 0.5, 1.0
+    model = {"kind": "LR", "params": {"theta": theta.astype(np.float32)},
+             "mu": np.zeros(f), "sd": np.ones(f), "y_scale": 10.0,
+             "resid_q": np.array([-0.1, 0.1])}
+    for name in run.names:
+        run.castor.versions.save(name, model, trained_at=run.now0)
+    return [model] * len(run.names)
+'''
+
+
+def test_a_new_model_takes_new_files_only(tmp_path, monkeypatch):
+    """A configuration of a new reference kind brings its own
+    ``bench/models/<kind>.py`` (comparison, control, one seeded version
+    shared by every deployment, operations): the harness drives its cell
+    on the CPU without touching any existing file."""
+    import calibrate
+    import checks
+    b = tmp_path / "bench"
+    for d in ("configs", "mixes", "limits", "metrics", "models"):
+        shutil.copytree(ROOT / "bench" / d, b / d)
+    (b / "models" / "shared_lr.py").write_text(SHARED_LR)
+    cfg = json.loads((b / "configs" / "lr_ridge.json").read_text())
+    cfg.update(name="lr_shared", reference="shared_lr", user_params={
+        **cfg["user_params"], "train_window_days": 7})
+    (b / "configs" / "lr_shared.json").write_text(json.dumps(cfg))
+    mix = json.loads((b / "mixes" / "hourly_score_4096.json").read_text())
+    mix["site"].update(n_prosumers=5, n_feeders=2)
+    mix.update(history_days=9, check_ticks=3, weights="seed")
+    del mix["train_every_h"]
+    (b / "mixes" / "tiny_seeded.json").write_text(json.dumps(mix))
+    (b / "limits" / "lr_shared.tiny_seeded.json").write_text(json.dumps(
+        {"unpersisted": 0, "forecast_gap": 1e-3}))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": "lr_shared", "source": "x",
+                            "file": "bench/configs/lr_shared.json",
+                            "reduced": [], "why": "x"})
+    spec["workloads"].append({"name": "lr_shared.tiny_seeded",
+                              "config": "lr_shared", "traffic": "tiny_seeded",
+                              "chips": 1, "why": "x"})
+    spec["per_layer"].append({"name": "score_mfu.shared", "unit": "%",
+                              "better": "higher", "source": "device_trace",
+                              "layer": "x", "moves": "jobs_per_s",
+                              "workloads": ["lr_shared.tiny_seeded"]})
+    shutil.copy(b / "metrics" / "score_mfu.py",
+                b / "metrics" / "score_mfu.shared.py")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    cell = bench.load_cell(tmp_path, "lr_shared.tiny_seeded", bench=b)
+    found = []
+    collect = checks.collect
+    monkeypatch.setattr(checks, "collect",
+                        lambda run: found.append(collect(run)) or found[-1])
+    out = bench.execute(tmp_path, cell, 3, 0.3, 0, {}, require_chip=False)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["checks"]) == {"unpersisted", "forecast_gap"}
+    assert cell["model"].CALLS == [3]
+    versions = found[0]["versions"]
+    assert len({id(v.params) for vs in versions for v in vs}) == 1
+    program, control = calibrate.readings(found[0], cell)
+    assert checks.decide(program) and set(control) == {"forecast_gap"}
+
+    run = SimpleNamespace(trace={"busy_s": {"/device:TPU:0": 0.5},
+                                 "window_s": 2.0},
+                          traced_ticks=4, config=cell["config"],
+                          site=SimpleNamespace(n=5), cell=cell,
+                          peaks={"flops_per_s": 1e9})
+    want = 100.0 * (2.0 * 55 * 5 * 24) * 4 / 2.0 / 1e9
+    got = bench.load_reader("score_mfu.shared", b)(run)
+    assert got == pytest.approx(want, rel=1e-12)
+
+
+def test_a_seeded_mix_needs_the_models_seed_versions():
+    cell = bench.load_cell(ROOT, "lr.hourly_score_4096")
+    cell["traffic"]["weights"] = "seed"
+    with pytest.raises(SystemExit, match=r"lr\.py has no seed_versions"):
+        bench.Run(cell, 1, require_chip=False)
+
+
+@pytest.mark.parametrize("cell", ["ann.cyprus531_score",
+                                  "lr.hourly_score_4096"])
+def test_score_mfu_takes_the_models_operations(cell):
+    """The ANN's whole score step is ``work.ann_score_flops`` a tick; LR
+    counts none, and the reader reads nothing there."""
+    import work
+    c = bench.load_cell(ROOT, cell)
+    run = SimpleNamespace(trace={"busy_s": {"/device:TPU:0": 1.5},
+                                 "window_s": 3.0},
+                          traced_ticks=40, config=c["config"],
+                          site=SimpleNamespace(n=531), cell=c,
+                          peaks=bench.peak_of("TPU v5 lite"))
+    got = bench.load_reader("score_mfu")(run)
+    if c["config"]["reference"] == "lr":
+        assert got is None
+        return
+    want = work.ann_score_flops(531, 54, 512, 4, 24) * 40 / 3.0 \
+        / 197e12 * 100.0
+    assert got == pytest.approx(want, rel=1e-12)
+
+
 def test_tick_tail_reads_the_ticks_after_the_profiled_ones():
-    from types import SimpleNamespace
     read = bench.load_reader("tick_p90_ms.score")
     ticks = [{"tick_s": 9.0}] * 5 + [{"tick_s": 0.1 + 0.001 * i}
                                      for i in range(40)]
